@@ -1,6 +1,7 @@
 """The paper's preemption point at kernel level: a GEMM that stops and
 resumes at K-tile boundaries, with the partial accumulator as the
-checkpointed ACCQ state (Pallas kernel, interpret mode on CPU).
+checkpointed ACCQ state.  The Pallas kernel runs compiled on a TPU and in
+interpret mode anywhere else.
 
     PYTHONPATH=src python examples/preemptible_kernel_demo.py
 """
@@ -17,6 +18,7 @@ def main():
     x = jax.random.normal(k1, (m, k), jnp.float32)
     y = jax.random.normal(k2, (k, n), jnp.float32)
 
+    interpret = jax.default_backend() != "tpu"
     ck = start(x, y)
     print(f"GEMM {m}x{k}x{n}: {ck.n_ktiles} K-tiles; "
           f"checkpoint = {ck.context_bytes()/1024:.0f} KiB accumulator")
@@ -24,7 +26,7 @@ def main():
     quantum = 2  # K tiles per scheduling quantum
     step = 0
     while not ck.done:
-        ck = advance(ck, x, y, n_tiles=quantum)
+        ck = advance(ck, x, y, n_tiles=quantum, interpret=interpret)
         step += 1
         print(f"  quantum {step}: k_tile={ck.k_tile}/{ck.n_ktiles} "
               f"(preempt here — context is ACCQ + tile index)")

@@ -1,7 +1,8 @@
 """Quickstart: serve two models on one engine under PREMA scheduling.
 
-Runs entirely on CPU with reduced configs; the same code drives a TPU pod
-(models are pure JAX; the engine schedules step boundaries).
+Runs reduced configs on JAX's default device: the CPU with
+``JAX_PLATFORMS=cpu``, else the TPU chip.  ``chip_smoke.py`` drives the same
+path at the full width of olmo-1b.
 
     PYTHONPATH=src python examples/quickstart.py
 """

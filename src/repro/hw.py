@@ -6,7 +6,10 @@ Two instantiations matter:
   Used by the figure-reproduction benchmarks so the simulator reproduces the
   paper's numbers on the paper's hardware.
 * ``TPU_V5E``  — the deployment target of this framework.  Its constants feed
-  the roofline analysis (EXPERIMENTS.md) and the serving engine's predictor.
+  the roofline analysis and the serving engine's predictor.
+
+:func:`for_device_kind` maps a JAX device's ``device_kind`` to its model; a
+kind that is not in :data:`DEVICE_KINDS` is an error, never a default.
 
 The analytical latency model (core/predictor.py) is parameterized by a
 ``HardwareModel`` so that the same Algorithm-1 code serves both.
@@ -69,8 +72,8 @@ PAPER_NPU = HardwareModel(
     bytes_per_elem=2,
 )
 
-# TPU v5e-like part (the roofline constants mandated for this project):
-#   197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI, 16 GiB HBM.
+# TPU v5e.  Peaks per chip from the Google Cloud documentation, "TPU v5e":
+#   197 TFLOP/s bf16, 819 GB/s HBM, 16 GB HBM, 1,600 Gbit/s ICI (4 links).
 # 4 MXUs of 128x128 @ ~940 MHz gives 4*16384*2*0.94e9 = 123 TF; to match the
 # given 197 TF peak we model the MXU clock at the effective rate
 # 197e12 / (2 * 4 * 128 * 128) = 1.503 GHz.  Only the *product* matters for
@@ -97,9 +100,17 @@ HBM_BW = 819e9
 ICI_BW_PER_LINK = 50e9
 
 
-def get_hw(name: str) -> HardwareModel:
-    if name in ("paper", "paper-npu", "npu"):
-        return PAPER_NPU
-    if name in ("tpu", "tpu-v5e", "v5e"):
-        return TPU_V5E
-    raise KeyError(f"unknown hardware model: {name!r}")
+# Hardware models keyed by ``jax.Device.device_kind`` (the string the TPU
+# runtime and the TPU compiler report for the chip).
+DEVICE_KINDS = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def for_device_kind(kind: str) -> HardwareModel:
+    """The hardware model of a chip, by its ``device_kind``."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise KeyError(f"no hardware model for device kind {kind!r}; "
+                       f"known: {sorted(DEVICE_KINDS)}") from None

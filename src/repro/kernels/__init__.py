@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU):
+"""Pallas TPU kernels (compiled for the chip by default; tests pass
+``interpret=True`` to run them on the CPU):
 
 * ``preemptible_matmul`` — K-tile-resumable GEMM (the paper's GEMM_OP
   preemption point; checkpoint = partial accumulator + tile index).

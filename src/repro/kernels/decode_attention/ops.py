@@ -12,7 +12,7 @@ from repro.kernels.decode_attention.kernel import decode_attention_raw
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      pos: jax.Array, bt: int = 512,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """q: (B,Hq,D) one token per sequence; k,v: (B,Hkv,T,D); pos scalar.
     Returns (B,Hq,D)."""
     b, hq, d = q.shape

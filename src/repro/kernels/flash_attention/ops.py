@@ -13,7 +13,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_raw
                    static_argnames=("causal", "bq", "bt", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, bq: int = 128, bt: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: (B,Hq,S,D); k,v: (B,Hkv,T,D) → (B,Hq,S,D).
 
     Pads S and T up to block multiples; padded keys are masked inside the

@@ -52,7 +52,7 @@ def start(x: jax.Array, y: jax.Array, bm: int = 128, bn: int = 128,
 
 def advance(ck: MatmulCheckpoint, x: jax.Array, y: jax.Array,
             n_tiles: int, bm: int = 128, bn: int = 128, bk: int = 128,
-            interpret: bool = True) -> MatmulCheckpoint:
+            interpret: bool = False) -> MatmulCheckpoint:
     """Execute up to ``n_tiles`` more K tiles (one scheduling quantum)."""
     xp = _pad_to(x, bm, bk)
     yp = _pad_to(y, bk, bn)
@@ -72,7 +72,7 @@ def finish(ck: MatmulCheckpoint, out_dtype=jnp.float32) -> jax.Array:
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret",
                                              "out_dtype"))
 def matmul(x: jax.Array, y: jax.Array, bm: int = 128, bn: int = 128,
-           bk: int = 128, interpret: bool = True,
+           bk: int = 128, interpret: bool = False,
            out_dtype=None) -> jax.Array:
     """Full GEMM through the preemptible kernel (single launch)."""
     m, n = x.shape[0], y.shape[1]

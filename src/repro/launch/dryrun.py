@@ -267,8 +267,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-        cost = cost[0] if cost else {}
     hlo_text = compiled.as_text()
     coll_raw = collective_bytes(hlo_text)
     corr = hlo_analysis.analyze(hlo_text)   # trip-count-corrected

@@ -6,27 +6,17 @@ module never touches jax device state.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.4.31; older versions have no explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-
-def _axis_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests use small virtual meshes)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -1,5 +1,7 @@
 """Serving launcher: bring up a PREMA engine over registered models and
-replay a request trace (synthetic or from a JSON file).
+replay a request trace (synthetic or from a JSON file).  The engine's
+hardware model is the one :mod:`repro.hw` lists for the device JAX runs on;
+a device it does not list is an error.
 
     PYTHONPATH=src python -m repro.launch.serve --archs olmo-1b qwen3-8b \
         --n-requests 12 --policy prema --mechanism dynamic
@@ -12,6 +14,8 @@ import json
 import jax
 import numpy as np
 
+from repro.hw import for_device_kind
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
 from repro.serving import EngineConfig, InferenceRequest, ServingEngine
 
@@ -30,13 +34,15 @@ def main():
     ap.add_argument("--out", default=None, help="write results JSON here")
     args = ap.parse_args()
 
+    enable_compile_cache()
+    hw = for_device_kind(jax.devices()[0].device_kind)
     key = jax.random.PRNGKey(0)
     models = {}
     for name in args.archs:
         m = get_model(name, tiny=True)
         models[name] = (m, m.init_params(key))
     engine = ServingEngine(models, cfg=EngineConfig(
-        policy=args.policy, preemptive=not args.non_preemptive,
+        hw=hw, policy=args.policy, preemptive=not args.non_preemptive,
         mechanism=args.mechanism))
     for name in args.archs:
         engine.fit_length_regressor(name, [(6, 3), (8, 4), (12, 6), (16, 8)])

@@ -2,9 +2,9 @@
 
 Wires the full substrate: config → mesh → sharded init (or elastic
 checkpoint restore) → jitted train_step with donation → data pipeline →
-periodic async checkpoints.  On this CPU container it runs reduced configs
-end-to-end; on a pod the same script runs the full ones (the mesh and
-shardings are identical — that is what the dry-run proves).
+periodic async checkpoints.  ``--tiny`` runs reduced configs end to end on
+the CPU; the full configs need a multi-chip mesh, which the dry-run compiles
+for but which no chip run has exercised.
 
     PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --tiny \
         --steps 50 --ckpt-dir /tmp/ckpt

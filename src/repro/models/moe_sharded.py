@@ -35,17 +35,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import ArchConfig
 from repro.distributed.context import ShardCtx
 
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version shim: ``jax.shard_map(..., check_vma=False)`` on new jax,
-    ``jax.experimental.shard_map.shard_map(..., check_rep=False)`` on old."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
 TP = "model"
 
 
@@ -146,8 +135,8 @@ def moe_ffn_psum(x2d: jax.Array, p: dict, cfg: ArchConfig,
 
     w_gate = p.get("w_gate", p["w_in"])
     fs = fsdp if fsdp else None
-    y, aux = _shard_map(
-        local, mesh=mesh,
+    y, aux = jax.shard_map(
+        local, mesh=mesh, check_vma=False,
         in_specs=(P(None, fs), P(fs, None),
                   P(TP, fs, None), P(TP, fs, None), P(TP, None, fs)),
         out_specs=(P(None, fs), P()),
@@ -229,8 +218,8 @@ def moe_ffn_sharded(x2d: jax.Array, p: dict, cfg: ArchConfig,
 
     w_gate = p.get("w_gate", p["w_in"])
     tok_spec = P(all_axes, None)
-    y, aux = _shard_map(
-        local, mesh=mesh,
+    y, aux = jax.shard_map(
+        local, mesh=mesh, check_vma=False,
         in_specs=(tok_spec, P(None, None),
                   P(TP, fsdp if fsdp else None, None),
                   P(TP, fsdp if fsdp else None, None),

@@ -1,10 +1,12 @@
 """Multi-tenant serving engine: PREMA scheduling over real JAX execution.
 
 The engine advances a *virtual clock* using the Algorithm-1 predicted cost
-of each executed step (this container has no TPU; on hardware the same loop
-uses measured step times), while the tensors themselves are computed for
-real by :class:`PreemptibleExecutor` — so scheduling behavior and model
-outputs are both exact and testable.
+of each executed step, on every platform: step times are not measured yet.
+The tensors themselves are computed for real by
+:class:`PreemptibleExecutor` on JAX's default device (a TPU chip, or the
+CPU in tests) — so scheduling behavior and model outputs are both exact and
+testable.  Engine devices (``n_devices``) are virtual: all of them compute
+on that one default device.
 
 Scheduling decisions (policy wake-up, candidate selection,
 ``Policy.may_preempt``, Algorithm-3 mechanism choice, KILL progress

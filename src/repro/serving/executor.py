@@ -9,13 +9,14 @@ pytree (:class:`ExecState`).  Suspend/resume is exact: a preempted-then-
 resumed run produces bit-identical outputs to an uninterrupted one
 (tests/test_serving.py).
 
-The per-period function is jitted once per model and reused across periods
-(parameters for period *i* are sliced out of the stacked pytree), so
-repeated preemption never triggers recompilation.
+The per-period function is jitted once per config and reused across periods
+(parameters for period *i* are sliced out of the stacked pytree and passed
+in), so repeated preemption never triggers recompilation.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -62,6 +63,39 @@ class ExecState:
         return int(sum(a.size * a.dtype.itemsize for a in leaves))
 
 
+# The jitted steps take the weights as arguments (never as closed-over
+# constants, which XLA would embed in the compiled program) and the config
+# as a static argument, so every executor of one config shares one compiled
+# program per input shape.
+@functools.partial(jax.jit, static_argnames="cfg")
+def _embed(params, batch, cfg: ArchConfig):
+    return transformer._embed_inputs(params, cfg, batch)
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _period_prefill(slots_slice, h, img_h, cfg: ArchConfig):
+    new_cache = {}
+    for i in range(cfg.period):
+        h, nc, _ = transformer._apply_block(
+            i, h, slots_slice[f"slot{i}"], cfg, "prefill", None, None, img_h)
+        if nc is not None:
+            new_cache[f"slot{i}"] = nc
+    return h, new_cache
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _finalize_prefill(params, h, cfg: ArchConfig):
+    hn = apply_norm(h, params["final_norm"], cfg)
+    if cfg.embedding_inputs:
+        return jnp.einsum("bsd,dv->bsv", hn, params["lm_head"]["w"])
+    return unembed(hn[:, -1:], params, cfg)
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def _decode(params, cache, tokens, pos, cfg: ArchConfig):
+    return transformer.decode_step(params, cache, tokens, pos, cfg)
+
+
 class PreemptibleExecutor:
     """Period/token-granular executor for one model instance."""
 
@@ -69,38 +103,6 @@ class PreemptibleExecutor:
         self.model = model
         self.cfg: ArchConfig = model.cfg
         self.params = params
-        cfg = self.cfg
-
-        @jax.jit
-        def _embed(batch):
-            return transformer._embed_inputs(params, cfg, batch)
-
-        @jax.jit
-        def _period_prefill(slots_slice, h, img_h):
-            new_cache = {}
-            for i in range(cfg.period):
-                h, nc, _ = transformer._apply_block(
-                    i, h, slots_slice[f"slot{i}"], cfg, "prefill", None,
-                    None, img_h)
-                if nc is not None:
-                    new_cache[f"slot{i}"] = nc
-            return h, new_cache
-
-        @jax.jit
-        def _finalize_prefill(h):
-            hn = apply_norm(h, params["final_norm"], cfg)
-            if cfg.embedding_inputs:
-                return jnp.einsum("bsd,dv->bsv", hn, params["lm_head"]["w"])
-            return unembed(hn[:, -1:], params, cfg)
-
-        @jax.jit
-        def _decode(cache, tokens, pos):
-            return transformer.decode_step(params, cache, tokens, pos, cfg)
-
-        self._embed = _embed
-        self._period_prefill = _period_prefill
-        self._finalize_prefill = _finalize_prefill
-        self._decode = _decode
 
     # ------------------------------------------------------------------
     @property
@@ -108,7 +110,7 @@ class PreemptibleExecutor:
         return self.cfg.n_periods
 
     def start(self, batch: Dict[str, jax.Array]) -> ExecState:
-        h, img_h = self._embed(batch)
+        h, img_h = _embed(self.params, batch, self.cfg)
         return ExecState(phase="prefill", period_idx=0, h=h, img_h=img_h,
                          cache_slices=[], tokens_out=[],
                          pos=int(h.shape[1]))
@@ -119,13 +121,13 @@ class PreemptibleExecutor:
     def step_prefill(self, st: ExecState) -> ExecState:
         """Execute one super-block period; boundary afterwards."""
         assert st.phase == "prefill"
-        h, cache_slice = self._period_prefill(
-            self._slots_slice(st.period_idx), st.h, st.img_h)
+        h, cache_slice = _period_prefill(
+            self._slots_slice(st.period_idx), st.h, st.img_h, self.cfg)
         st.h = h
         st.cache_slices.append(cache_slice)
         st.period_idx += 1
         if st.period_idx == self.n_periods:
-            st.last_logits = self._finalize_prefill(st.h)
+            st.last_logits = _finalize_prefill(self.params, st.h, self.cfg)
             if self.cfg.encoder_only:
                 st.phase = "done"
             else:
@@ -170,7 +172,8 @@ class PreemptibleExecutor:
         if t_cap is not None and st.pos >= t_cap:
             self._grow_cache(st, max(16, t_cap // 4))
         tok = jnp.asarray(st.tokens_out[-1][:, None])
-        logits, st.cache = self._decode(st.cache, tok, jnp.int32(st.pos))
+        logits, st.cache = _decode(self.params, st.cache, tok,
+                                  jnp.int32(st.pos), self.cfg)
         st.pos += 1
         st.last_logits = logits
         nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
@@ -200,9 +203,9 @@ class PreemptibleExecutor:
 
     @staticmethod
     def checkpoint(st: ExecState) -> ExecState:
-        """Materialize the context (device→host in a real deployment).  On
-        the CPU backend arrays are already host-resident; we block on async
-        dispatch so the checkpoint is a complete, consistent snapshot."""
+        """Block on async dispatch so the checkpoint is a complete,
+        consistent snapshot.  The context stays where it was computed
+        (device memory on a chip); nothing is copied to the host."""
         for leaf in jax.tree.leaves((st.h, st.cache, st.cache_slices,
                                      st.last_logits)):
             if hasattr(leaf, "block_until_ready"):

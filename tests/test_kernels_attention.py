@@ -30,7 +30,8 @@ def test_flash_vs_oracle(case, dtype, key):
     q = jax.random.normal(ks[0], (b, hq, s, d), dtype)
     k = jax.random.normal(ks[1], (b, hkv, t, d), dtype)
     v = jax.random.normal(ks[2], (b, hkv, t, d), dtype)
-    out = flash_attention(q, k, v, causal=causal, bq=64, bt=64)
+    out = flash_attention(q, k, v, causal=causal, bq=64, bt=64,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=causal)
     tol = 3e-2 if dtype == jnp.bfloat16 else 3e-4
     np.testing.assert_allclose(
@@ -53,7 +54,8 @@ def test_decode_vs_oracle(case, key):
     q = jax.random.normal(ks[0], (b, hq, d), jnp.float32)
     k = jax.random.normal(ks[1], (b, hkv, t, d), jnp.float32)
     v = jax.random.normal(ks[2], (b, hkv, t, d), jnp.float32)
-    out = decode_attention(q, k, v, jnp.int32(pos), bt=256)
+    out = decode_attention(q, k, v, jnp.int32(pos), bt=256,
+                           interpret=True)
     g = hq // hkv
     ref = decode_attention_ref(q.reshape(b, hkv, g, d), k, v,
                                pos).reshape(b, hq, d)
@@ -69,7 +71,7 @@ def test_decode_pos_zero_attends_only_first(key):
     q = jax.random.normal(ks[0], (b, hq, d), jnp.float32)
     k = jax.random.normal(ks[1], (b, hkv, t, d), jnp.float32)
     v = jax.random.normal(ks[2], (b, hkv, t, d), jnp.float32)
-    out = decode_attention(q, k, v, jnp.int32(0), bt=64)
+    out = decode_attention(q, k, v, jnp.int32(0), bt=64, interpret=True)
     expect = jnp.repeat(v[:, :, 0], hq // hkv, axis=1).reshape(b, hq, d)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-5, atol=1e-5)
@@ -86,7 +88,8 @@ def test_property_flash_random(s, d, hkv, g, seed):
     q = jax.random.normal(ks[0], (1, hq, s, d), jnp.float32)
     k = jax.random.normal(ks[1], (1, hkv, s, d), jnp.float32)
     v = jax.random.normal(ks[2], (1, hkv, s, d), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, bq=32, bt=32)
+    out = flash_attention(q, k, v, causal=True, bq=32, bt=32,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-3, atol=1e-3)

@@ -26,7 +26,7 @@ def test_matmul_matches_oracle(shape, dtype, key):
     k1, k2 = jax.random.split(key)
     x = jax.random.normal(k1, (m, k), dtype)
     y = jax.random.normal(k2, (k, n), dtype)
-    out = matmul(x, y, out_dtype=jnp.float32)
+    out = matmul(x, y, out_dtype=jnp.float32, interpret=True)
     ref = matmul_ref(x, y, out_dtype=jnp.float32)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-3
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -40,12 +40,12 @@ def test_resume_equals_uninterrupted_bitwise(key):
     x = jax.random.normal(k1, (256, 640, ), jnp.float32).reshape(256, 640)
     y = jax.random.normal(k2, (640, 256), jnp.float32)
     one = start(x, y)
-    one = advance(one, x, y, n_tiles=one.n_ktiles)
+    one = advance(one, x, y, n_tiles=one.n_ktiles, interpret=True)
     ref = finish(one)
 
     chunked = start(x, y)
     for step in (1, 2, 1, 1):  # arbitrary preemption pattern
-        chunked = advance(chunked, x, y, n_tiles=step)
+        chunked = advance(chunked, x, y, n_tiles=step, interpret=True)
     out = finish(chunked)
     assert np.array_equal(np.asarray(out), np.asarray(ref))
 
@@ -55,7 +55,7 @@ def test_partial_accumulator_matches_partial_ref(key):
     x = jax.random.normal(k1, (128, 512), jnp.float32)
     y = jax.random.normal(k2, (512, 128), jnp.float32)
     ck = start(x, y)
-    ck = advance(ck, x, y, n_tiles=2)     # K tiles [0, 2)
+    ck = advance(ck, x, y, n_tiles=2, interpret=True)  # K tiles [0, 2)
     ref = matmul_partial_ref(x, y, jnp.zeros((128, 128), jnp.float32), 0, 2)
     np.testing.assert_allclose(np.asarray(ck.acc[:128, :128]),
                                np.asarray(ref), rtol=1e-4, atol=1e-4)
@@ -76,7 +76,7 @@ def test_property_random_shapes(m, k, n, seed):
     k1, k2 = jax.random.split(kk)
     x = jax.random.normal(k1, (m, k), jnp.float32)
     y = jax.random.normal(k2, (k, n), jnp.float32)
-    out = matmul(x, y, out_dtype=jnp.float32)
+    out = matmul(x, y, out_dtype=jnp.float32, interpret=True)
     ref = matmul_ref(x, y, out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
