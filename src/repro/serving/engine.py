@@ -34,6 +34,18 @@ request finish.
 A ``straggler_factor`` hook perturbs realized step times (fault injection);
 the predictive scheduler observes only predictions, so tests can verify
 PREMA's robustness to mispredicted/straggling steps.
+
+In execute mode both loops mark their phases on the wall clock with
+``jax.profiler.TraceAnnotation`` spans, recorded on the device trace's
+clock while a profiler trace is being taken: ``engine.iteration`` (one
+pass of the loop), ``engine.ingest``, ``engine.decide`` (the arbiter's
+wake/pick/arbitrate and the placement), ``engine.preempt`` (from the
+PREEMPT decision to the new request's ``begin``), ``engine.begin`` and
+``engine.complete``, the last three with the request's ``rid`` (and
+``slot`` on the batched path) as arguments; and the gauge
+``engine.parked_bytes:<n>`` (:class:`_ParkedBytes`).  The executor adds
+its own (``serving/executor.py``).  Virtual mode emits none and wraps
+nothing: its time is the virtual clock's, on the event bus.
 """
 from __future__ import annotations
 
@@ -43,6 +55,7 @@ import warnings
 from typing import Callable, Dict, List, Optional, Tuple, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import arch_ops, metrics, preemption
 from repro.core import events as events_mod
@@ -110,6 +123,57 @@ class _Job:
     decode_step_time: float = 0.0
     first_token_time: Optional[float] = None
     result: Optional[RequestResult] = None
+
+
+def _spanned(fn: Callable, name: str,
+             args: Optional[Callable[..., dict]] = None) -> Callable:
+    """``fn`` run inside a ``TraceAnnotation`` named ``name``, whose
+    arguments ``args`` computes from ``fn``'s."""
+    if args is None:
+        def run(*a):
+            with TraceAnnotation(name):
+                return fn(*a)
+    else:
+        def run(*a):
+            with TraceAnnotation(name, **args(*a)):
+                return fn(*a)
+    return run
+
+
+def _rid(d: int, j: _Job, *rest) -> dict:
+    return {"rid": j.req.rid}
+
+
+def _rid_slot(d: int, si: int, j: _Job, *rest) -> dict:
+    return {"rid": j.req.rid, "slot": si}
+
+
+PARKED_BYTES = "engine.parked_bytes:"
+
+
+class _ParkedBytes:
+    """Device bytes held by started requests that are not running
+    (:meth:`ExecState.device_bytes` of each one's checkpointed state), as
+    a gauge in the profiler trace: once when a run starts and at every
+    change, a zero-length span named ``engine.parked_bytes:<n>`` (the
+    value goes in the name, as a trace's reduction keeps names)."""
+    __slots__ = ("_by_rid",)
+
+    def __init__(self):
+        self._by_rid: Dict[int, int] = {}
+        self._emit()
+
+    def park(self, j: _Job) -> None:
+        self._by_rid[j.req.rid] = j.state.device_bytes()
+        self._emit()
+
+    def resume(self, j: _Job) -> None:
+        if self._by_rid.pop(j.req.rid, None) is not None:
+            self._emit()
+
+    def _emit(self) -> None:
+        with TraceAnnotation(f"{PARKED_BYTES}{sum(self._by_rid.values())}"):
+            pass
 
 
 class _ReadyJobs:
@@ -413,6 +477,7 @@ class ServingEngine:
         # dropped, retried, and later completed settles exactly once)
         settled_rids: set = set()
         recorded: set = set()              # rids appended to self.tasks
+        parked = _ParkedBytes() if self.execute else None
 
         def record(j: _Job) -> None:
             if j.req.rid not in recorded:
@@ -522,6 +587,7 @@ class ServingEngine:
                 dev_clock[d] += lat
                 if self.execute and j.state is not None:
                     j.state = PreemptibleExecutor.restore(j.state)
+                    parked.resume(j)
             if j.state is None and self.execute:
                 j.state = j.executor.start(self._batch_dict(j.req))
                 self.kvs[d].register(j.req.rid, 0, dev_clock[d])
@@ -542,6 +608,7 @@ class ServingEngine:
             lat = preemption.checkpoint_latency(t, dev_hw(d))
             if self.execute and j.state is not None:
                 j.state = PreemptibleExecutor.checkpoint(j.state)
+                parked.park(j)
                 lat += self.kvs[d].resize(j.req.rid, j.state.cache_bytes(),
                                           dev_clock[d])
             t.checkpoint_overhead += lat
@@ -685,85 +752,118 @@ class ServingEngine:
             return (d.alive and not d.failed
                     and (running[i] is not None or not d.draining))
 
+        def decide_idle(d: int, now: float):
+            """The policy's candidate for idle device ``d`` and, among the
+            devices free *now*, the one placement gives it (affinity avoids
+            a cross-chip resume); ``(None, d)`` when the policy abstains."""
+            cand = pick(d)
+            if cand is None:
+                return None, d
+            free = [devices[i] for i in range(len(devices))
+                    if running[i] is None and devices[i].schedulable(now)
+                    and dev_clock[i] <= now + 1e-15]
+            target = (self.cluster.choose(cand.task, free, now).dev
+                      if len(free) > 1 else d)
+            return cand, target
+
+        def decide_at_boundary(d: int, j: _Job):
+            """The policy's candidate at ``j``'s step boundary and the
+            arbiter's decision between the two (None without a rival)."""
+            cand = pick(d)
+            if cand is None or cand is j:
+                return cand, None
+            return cand, self.arbiter.arbitrate(j.task, cand.task)
+
+        def preempt(d: int, victim: _Job, cand: _Job, mech: Mechanism):
+            """Take ``victim`` off device ``d`` by ``mech`` and start or
+            resume ``cand`` there."""
+            bus.preempt(dev_clock[d], victim.task, d, mech.value)
+            if mech is Mechanism.KILL:
+                do_kill(d, victim)
+            else:
+                do_checkpoint(d, victim)
+            devices[d].running = None
+            ready.append(victim)
+            victim.task.last_wake = dev_clock[d]
+            ready.remove(cand)
+            begin(d, cand)
+
+        def drain_out(d: int, j: _Job, now: float):
+            """A draining device gives up its resident at the step
+            boundary: checkpoint out, resume elsewhere (migration)."""
+            bus.preempt(now, j.task, d, Mechanism.CHECKPOINT.value)
+            do_checkpoint(d, j)
+            devices[d].running = None
+            running[d] = None
+            ready.append(j)
+            j.task.last_wake = dev_clock[d]
+            settle_drain(d, dev_clock[d])
+
+        def iteration():
+            nonlocal clock
+            cands = [i for i in range(len(devices)) if selectable(i)]
+            assert cands, "engine has no schedulable devices left"
+            d = min(cands,
+                    key=lambda i: (dev_clock[i],
+                                   0 if running[i] is not None else 1, i))
+            now = clock = dev_clock[d]
+            ingest(now)
+            j = running[d]
+            if j is None:
+                if not ready:
+                    if arrivals:
+                        dev_clock[d] = max(now, arrivals[0][0])
+                    else:
+                        # nothing to do on this device until another one
+                        # finishes or preempts; follow the busy clocks
+                        busy = [dev_clock[i] for i in cands
+                                if running[i] is not None]
+                        assert busy, "engine stalled with work outstanding"
+                        dev_clock[d] = max(now, min(busy))
+                    return
+                cand, target = decide_idle(d, now)
+                if cand is None:
+                    # policy abstained with a non-empty queue: advance to
+                    # the next arrival, or by one scheduling quantum when
+                    # there is none (anti-livelock; the old loop spun here)
+                    if arrivals:
+                        dev_clock[d] = max(now, arrivals[0][0])
+                    else:
+                        dev_clock[d] = now + SCHED_QUANTUM
+                    return
+                ready.remove(cand)
+                dev_clock[target] = max(dev_clock[target], now)
+                begin(target, cand)
+                return
+            if devices[d].draining:
+                drain_out(d, j, now)
+                return
+            # at a step boundary: consider preemption, then run one step
+            if ready and self.policy.preemptive:
+                cand, dec = decide_at_boundary(d, j)
+                if dec is not None and dec.action is Action.PREEMPT:
+                    preempt(d, j, cand, dec.mechanism)
+            j = running[d]
+            exec_one_step(d, j)
+            if step_done(j):
+                complete(d, j)
+                settle_drain(d, dev_clock[d])
+
+        if self.execute:
+            iteration = _spanned(iteration, "engine.iteration")
+            ingest = _spanned(ingest, "engine.ingest")
+            decide_idle = _spanned(decide_idle, "engine.decide")
+            decide_at_boundary = _spanned(decide_at_boundary, "engine.decide")
+            preempt = _spanned(preempt, "engine.preempt", _rid)
+            drain_out = _spanned(drain_out, "engine.preempt", _rid)
+            begin = _spanned(begin, "engine.begin", _rid)
+            complete = _spanned(complete, "engine.complete", _rid)
+
         # closed-loop hooks can grow ``jobs`` mid-run; a request settles
         # exactly once (complete, or a drop with no client retry)
         try:
             while len(settled_rids) < len(jobs):
-                cands = [i for i in range(len(devices)) if selectable(i)]
-                assert cands, "engine has no schedulable devices left"
-                d = min(cands,
-                        key=lambda i: (dev_clock[i],
-                                       0 if running[i] is not None else 1, i))
-                now = clock = dev_clock[d]
-                ingest(now)
-                j = running[d]
-                if j is None:
-                    if not ready:
-                        if arrivals:
-                            dev_clock[d] = max(now, arrivals[0][0])
-                        else:
-                            # nothing to do on this device until another one
-                            # finishes or preempts; follow the busy clocks
-                            busy = [dev_clock[i] for i in cands
-                                    if running[i] is not None]
-                            assert busy, "engine stalled with work outstanding"
-                            dev_clock[d] = max(now, min(busy))
-                        continue
-                    cand = pick(d)
-                    if cand is None:
-                        # policy abstained with a non-empty queue: advance to
-                        # the next arrival, or by one scheduling quantum when
-                        # there is none (anti-livelock; the old loop spun here)
-                        if arrivals:
-                            dev_clock[d] = max(now, arrivals[0][0])
-                        else:
-                            dev_clock[d] = now + SCHED_QUANTUM
-                        continue
-                    # among the devices free *now*, placement chooses which one
-                    # takes the candidate (affinity avoids a cross-chip resume)
-                    free = [devices[i] for i in range(len(devices))
-                            if running[i] is None and devices[i].schedulable(now)
-                            and dev_clock[i] <= now + 1e-15]
-                    target = (self.cluster.choose(cand.task, free, now).dev
-                              if len(free) > 1 else d)
-                    ready.remove(cand)
-                    dev_clock[target] = max(dev_clock[target], now)
-                    begin(target, cand)
-                    continue
-                # a draining device gives up its resident at the step
-                # boundary: checkpoint out, resume elsewhere (migration)
-                if devices[d].draining:
-                    bus.preempt(now, j.task, d, Mechanism.CHECKPOINT.value)
-                    do_checkpoint(d, j)
-                    devices[d].running = None
-                    running[d] = None
-                    ready.append(j)
-                    j.task.last_wake = dev_clock[d]
-                    settle_drain(d, dev_clock[d])
-                    continue
-                # at a step boundary: consider preemption, then run one step
-                if ready and self.policy.preemptive:
-                    cand = pick(d)
-                    if cand is not None and cand is not j:
-                        dec = self.arbiter.arbitrate(j.task, cand.task)
-                        if dec.action is Action.PREEMPT:
-                            victim = j
-                            bus.preempt(dev_clock[d], victim.task, d,
-                                        dec.mechanism.value)
-                            if dec.mechanism is Mechanism.KILL:
-                                do_kill(d, victim)
-                            else:
-                                do_checkpoint(d, victim)
-                            devices[d].running = None
-                            ready.append(victim)
-                            victim.task.last_wake = dev_clock[d]
-                            ready.remove(cand)
-                            begin(d, cand)
-                j = running[d]
-                exec_one_step(d, j)
-                if step_done(j):
-                    complete(d, j)
-                    settle_drain(d, dev_clock[d])
+                iteration()
         finally:
             self._inject = None   # dead runs must not accept submissions
             self._elastic = None
@@ -815,6 +915,7 @@ class ServingEngine:
         clock = 0.0
         settled_rids: set = set()
         recorded: set = set()
+        parked = _ParkedBytes() if self.execute else None
 
         # analytic KV accounting (both modes): prompt KV at admission,
         # one token's cache slice per resident per decode iteration
@@ -948,6 +1049,7 @@ class ServingEngine:
                 dev_clock[di] += lat
                 if self.execute and j.state is not None:
                     j.state = PreemptibleExecutor.restore(j.state)
+                    parked.resume(j)
             else:
                 dev_clock[di] += self.kvs[di].register(
                     j.req.rid, ctx_bytes(j), now)
@@ -971,6 +1073,7 @@ class ServingEngine:
             lat = preemption.checkpoint_latency(t, dev_hw(di))
             if self.execute and j.state is not None:
                 j.state = PreemptibleExecutor.checkpoint(j.state)
+                parked.park(j)
             lat += self.kvs[di].resize(j.req.rid, ctx_bytes(j), dev_clock[di])
             t.checkpoint_overhead += lat
             t.ckpt_executed = t.executed
@@ -1028,59 +1131,80 @@ class ServingEngine:
             end_slot(di, si)
             bus.complete(t_done, t, di, slot=si)
 
-        def try_fill(now: float) -> bool:
-            """One placement pass: admit the policy's top candidate into
-            a free slot anywhere in the cluster (role-compatible)."""
-            if not ready:
-                return False
+        def fill_decision(now: float):
+            """The policy's top candidate for a free slot anywhere in the
+            cluster and the device placement gives it (role-compatible),
+            or None."""
             free = [dv for dv in devices
                     if dv.schedulable(now)
                     and dev_clock[dv.dev] <= now + 1e-15
                     and free_slot_index(dv.dev) is not None]
             if not free:
-                return False
+                return None
             ts = [t for t in ready.tasks
                   if any(role_accepts(dv.role, t.phase) for dv in free)]
             if not ts:
-                return False
+                return None
             self.arbiter.wake(ready.tasks, now)
             sel = self.arbiter.pick(ts, now, None)
             if sel is None:
-                return False
-            j = ready.job_for(sel)
+                return None
             hosts = [dv for dv in free if role_accepts(dv.role, sel.phase)]
             target = (self.cluster.choose(sel, hosts, now)
                       if len(hosts) > 1 else hosts[0])
+            return ready.job_for(sel), target
+
+        def try_fill(now: float) -> bool:
+            """One placement pass: admit the policy's top candidate into
+            a free slot anywhere in the cluster (role-compatible)."""
+            if not ready:
+                return False
+            chosen = fill_decision(now)
+            if chosen is None:
+                return False
+            j, target = chosen
             ready.remove(j)
             si = free_slot_index(target.dev)
             dev_clock[target.dev] = max(dev_clock[target.dev], now)
             begin_slot(target.dev, si, j)
             return True
 
-        def try_preempt(di: int, now: float) -> None:
-            """All slots taken: let the arbiter displace the slot_victim."""
+        def preempt_decision(di: int, now: float):
+            """All slots taken: the arbiter's PREEMPT decision and its
+            slot_victim, or None."""
             dv = devices[di]
             res = [t for t in dv.residents if t is not None]
             ts = [t for t in ready.tasks if role_accepts(dv.role, t.phase)]
             if not ts or not res:
-                return
+                return None
             dec = self.arbiter.decide_batch(ts, now, res, 0)
             if dec.action is not Action.PREEMPT:
-                return
-            victim_t = self.arbiter.slot_victim(res)
-            si = dv.residents.index(victim_t)
-            vj = slots[di][si]
-            bus.preempt(now, victim_t, di, dec.mechanism.value, slot=si)
+                return None
+            return dec, self.arbiter.slot_victim(res)
+
+        def preempt_slot(di: int, si: int, vj: _Job, dec, now: float):
+            """Take ``vj`` out of slot ``si`` by ``dec``'s mechanism and
+            start or resume ``dec``'s candidate there."""
+            bus.preempt(now, vj.task, di, dec.mechanism.value, slot=si)
             if dec.mechanism is Mechanism.KILL:
                 do_kill(di, vj)
             else:
                 do_checkpoint(di, vj)
             end_slot(di, si)
             ready.append(vj)
-            victim_t.last_wake = dev_clock[di]
+            vj.task.last_wake = dev_clock[di]
             cj = ready.job_for(dec.cand)
             ready.remove(cj)
             begin_slot(di, si, cj)
+
+        def try_preempt(di: int, now: float) -> None:
+            """All slots taken: let the arbiter displace the slot_victim."""
+            decided = preempt_decision(di, now)
+            if decided is None:
+                return
+            dec, victim_t = decided
+            si = devices[di].residents.index(victim_t)
+            preempt_slot(di, si, slots[di][si], dec, now)
 
         def step_done(j: _Job) -> bool:
             t = j.task
@@ -1157,6 +1281,8 @@ class ServingEngine:
                     t.ckpt_executed = t.executed
                     t.restore_pending = True
                     t.state = TaskState.PREEMPTED
+                    if self.execute:
+                        parked.park(j)
                     end_slot(di, si)
                     ready.append(j)
                     t.last_wake = dev_clock[di]
@@ -1206,44 +1332,59 @@ class ServingEngine:
             return (d.alive and not d.failed
                     and (d.n_resident > 0 or not d.draining))
 
+        def iteration():
+            nonlocal clock
+            cands = [i for i in range(len(devices)) if selectable(i)]
+            assert cands, "engine has no schedulable devices left"
+            d = min(cands,
+                    key=lambda i: (dev_clock[i],
+                                   0 if devices[i].n_resident else 1, i))
+            now = clock = dev_clock[d]
+            ingest(now)
+            if devices[d].draining and devices[d].n_resident:
+                # iteration boundary on a draining device: every
+                # resident checkpoints out and resumes elsewhere
+                for si, j in [(si, j) for si, j in enumerate(slots[d])
+                              if j is not None]:
+                    evict_slot(d, si, j, now)
+                settle_drain(d, dev_clock[d])
+                return
+            while try_fill(now):
+                pass
+            if (ready and self.policy.preemptive
+                    and free_slot_index(d) is None):
+                try_preempt(d, now)
+            if devices[d].n_resident == 0:
+                if arrivals:
+                    dev_clock[d] = max(now, arrivals[0][0])
+                else:
+                    busy = [dev_clock[i] for i in cands
+                            if devices[i].n_resident]
+                    if busy:
+                        dev_clock[d] = max(now, min(busy))
+                    else:
+                        assert ready, \
+                            "engine stalled with work outstanding"
+                        # policy abstained (or no role-compatible
+                        # host): advance one quantum, anti-livelock
+                        dev_clock[d] = now + SCHED_QUANTUM
+                return
+            run_iteration(d)
+
+        if self.execute:
+            iteration = _spanned(iteration, "engine.iteration")
+            ingest = _spanned(ingest, "engine.ingest")
+            fill_decision = _spanned(fill_decision, "engine.decide")
+            preempt_decision = _spanned(preempt_decision, "engine.decide")
+            preempt_slot = _spanned(preempt_slot, "engine.preempt", _rid_slot)
+            evict_slot = _spanned(evict_slot, "engine.preempt", _rid_slot)
+            begin_slot = _spanned(begin_slot, "engine.begin", _rid_slot)
+            complete_slot = _spanned(complete_slot, "engine.complete",
+                                     _rid_slot)
+
         try:
             while len(settled_rids) < len(jobs):
-                cands = [i for i in range(len(devices)) if selectable(i)]
-                assert cands, "engine has no schedulable devices left"
-                d = min(cands,
-                        key=lambda i: (dev_clock[i],
-                                       0 if devices[i].n_resident else 1, i))
-                now = clock = dev_clock[d]
-                ingest(now)
-                if devices[d].draining and devices[d].n_resident:
-                    # iteration boundary on a draining device: every
-                    # resident checkpoints out and resumes elsewhere
-                    for si, j in [(si, j) for si, j in enumerate(slots[d])
-                                  if j is not None]:
-                        evict_slot(d, si, j, now)
-                    settle_drain(d, dev_clock[d])
-                    continue
-                while try_fill(now):
-                    pass
-                if (ready and self.policy.preemptive
-                        and free_slot_index(d) is None):
-                    try_preempt(d, now)
-                if devices[d].n_resident == 0:
-                    if arrivals:
-                        dev_clock[d] = max(now, arrivals[0][0])
-                    else:
-                        busy = [dev_clock[i] for i in cands
-                                if devices[i].n_resident]
-                        if busy:
-                            dev_clock[d] = max(now, min(busy))
-                        else:
-                            assert ready, \
-                                "engine stalled with work outstanding"
-                            # policy abstained (or no role-compatible
-                            # host): advance one quantum, anti-livelock
-                            dev_clock[d] = now + SCHED_QUANTUM
-                    continue
-                run_iteration(d)
+                iteration()
         finally:
             self._inject = None
             self._elastic = None

@@ -12,6 +12,14 @@ resumed run produces bit-identical outputs to an uninterrupted one
 The per-period function is jitted once per config and reused across periods
 (parameters for period *i* are sliced out of the stacked pytree and passed
 in), so repeated preemption never triggers recompilation.
+
+Each step is a ``jax.profiler.TraceAnnotation`` span on the host thread
+that runs it (``executor.prefill``, ``executor.first_token``,
+``executor.decode``, ``executor.grow_cache``, ``executor.sync``,
+``executor.checkpoint``): recorded on the device trace's clock while a
+profiler trace is being taken, and about a microsecond each otherwise.
+``executor.first_token`` and ``executor.decode`` end when their token is on
+the host.
 """
 from __future__ import annotations
 
@@ -22,6 +30,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import ArchConfig
 from repro.models import transformer
@@ -45,9 +54,9 @@ class ExecState:
     last_logits: Optional[jax.Array] = None
 
     def context_bytes(self) -> int:
-        """Size of the state a CHECKPOINT must preserve.  KV/SSM caches are
-        HBM-resident on TPU (not re-spilled); the live activation boundary
-        state is what moves."""
+        """Bytes of the activation boundary alone: the hidden activations
+        and the last logits.  The caches, which a CHECKPOINT keeps too,
+        are in :meth:`cache_bytes`; :meth:`device_bytes` counts both."""
         total = 0
         for arr in (self.h, self.last_logits):
             if arr is not None:
@@ -60,6 +69,14 @@ class ExecState:
             leaves += jax.tree.leaves(self.cache_slices)
         if self.cache is not None:
             leaves += jax.tree.leaves(self.cache)
+        return int(sum(a.size * a.dtype.itemsize for a in leaves))
+
+    def device_bytes(self) -> int:
+        """Bytes of every device array the state holds: ``h``, ``img_h``,
+        the prefill ``cache_slices``, the decode ``cache`` at its grown
+        capacity and ``last_logits`` (the tokens are on the host)."""
+        leaves = jax.tree.leaves((self.h, self.img_h, self.cache_slices,
+                                  self.cache, self.last_logits))
         return int(sum(a.size * a.dtype.itemsize for a in leaves))
 
 
@@ -121,16 +138,20 @@ class PreemptibleExecutor:
     def step_prefill(self, st: ExecState) -> ExecState:
         """Execute one super-block period; boundary afterwards."""
         assert st.phase == "prefill"
-        h, cache_slice = _period_prefill(
-            self._slots_slice(st.period_idx), st.h, st.img_h, self.cfg)
-        st.h = h
-        st.cache_slices.append(cache_slice)
-        st.period_idx += 1
-        if st.period_idx == self.n_periods:
-            st.last_logits = _finalize_prefill(self.params, st.h, self.cfg)
+        with TraceAnnotation("executor.prefill"):
+            h, cache_slice = _period_prefill(
+                self._slots_slice(st.period_idx), st.h, st.img_h, self.cfg)
+            st.h = h
+            st.cache_slices.append(cache_slice)
+            st.period_idx += 1
+            if st.period_idx < self.n_periods:
+                return st
             if self.cfg.encoder_only:
+                st.last_logits = _finalize_prefill(self.params, st.h, self.cfg)
                 st.phase = "done"
-            else:
+                return st
+            with TraceAnnotation("executor.first_token"):
+                st.last_logits = _finalize_prefill(self.params, st.h, self.cfg)
                 # stack per-period cache slices into the decode cache and
                 # greedy-sample the first token
                 st.cache = jax.tree.map(
@@ -138,8 +159,8 @@ class PreemptibleExecutor:
                 st.cache_slices = None
                 tok = np.asarray(jnp.argmax(st.last_logits[:, -1], axis=-1),
                                  np.int32)
-                st.tokens_out.append(tok)
-                st.phase = "decode"
+            st.tokens_out.append(tok)
+            st.phase = "decode"
         return st
 
     def _grow_cache(self, st: ExecState, extra: int):
@@ -158,26 +179,29 @@ class PreemptibleExecutor:
                 return jnp.pad(a, pad_width)
             return {k: pad(v) for k, v in slot_cache.items()}
 
-        st.cache = {k: grow_slot(k, v) for k, v in st.cache.items()}
+        with TraceAnnotation("executor.grow_cache"):
+            st.cache = {k: grow_slot(k, v) for k, v in st.cache.items()}
 
     def step_decode(self, st: ExecState) -> ExecState:
         """Generate one token; boundary afterwards."""
         assert st.phase == "decode"
-        t_cap = None
-        for name, slot in st.cache.items():
-            mixer = self.cfg.block_pattern[int(name[4:])][0]
-            if mixer == "attn":
-                t_cap = slot["k"].shape[2]
-                break
-        if t_cap is not None and st.pos >= t_cap:
-            self._grow_cache(st, max(16, t_cap // 4))
-        tok = jnp.asarray(st.tokens_out[-1][:, None])
-        logits, st.cache = _decode(self.params, st.cache, tok,
-                                  jnp.int32(st.pos), self.cfg)
-        st.pos += 1
-        st.last_logits = logits
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
-        st.tokens_out.append(nxt)
+        with TraceAnnotation("executor.decode"):
+            t_cap = None
+            for name, slot in st.cache.items():
+                mixer = self.cfg.block_pattern[int(name[4:])][0]
+                if mixer == "attn":
+                    t_cap = slot["k"].shape[2]
+                    break
+            if t_cap is not None and st.pos >= t_cap:
+                self._grow_cache(st, max(16, t_cap // 4))
+            tok = jnp.asarray(st.tokens_out[-1][:, None])
+            logits, st.cache = _decode(self.params, st.cache, tok,
+                                      jnp.int32(st.pos), self.cfg)
+            st.pos += 1
+            st.last_logits = logits
+            with TraceAnnotation("executor.sync"):
+                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+            st.tokens_out.append(nxt)
         return st
 
     def step(self, st: ExecState) -> ExecState:
@@ -206,10 +230,11 @@ class PreemptibleExecutor:
         """Block on async dispatch so the checkpoint is a complete,
         consistent snapshot.  The context stays where it was computed
         (device memory on a chip); nothing is copied to the host."""
-        for leaf in jax.tree.leaves((st.h, st.cache, st.cache_slices,
-                                     st.last_logits)):
-            if hasattr(leaf, "block_until_ready"):
-                leaf.block_until_ready()
+        with TraceAnnotation("executor.checkpoint"):
+            for leaf in jax.tree.leaves((st.h, st.cache, st.cache_slices,
+                                         st.last_logits)):
+                if hasattr(leaf, "block_until_ready"):
+                    leaf.block_until_ready()
         return st
 
     @staticmethod
