@@ -445,6 +445,40 @@ class ServingEngine:
             batch["img_embeds"] = req.img_embeds
         return batch
 
+    def _start(self, j: _Job) -> ExecState:
+        """The request's execution state, which learns its token budget
+        and stop token."""
+        return j.executor.start(self._batch_dict(j.req),
+                                max_new_tokens=j.req.max_new_tokens,
+                                eos_id=j.req.eos_id)
+
+    def _take_tokens(self, j: _Job) -> np.ndarray:
+        """A completed request's tokens, every one read to the host.  Its
+        execution state is dropped, so the job table, which outlives the
+        request, holds none of its device arrays (the KV cache above all)."""
+        st, j.state = j.state, None
+        if self.execute and st is not None:
+            PreemptibleExecutor.read_pending(st)
+            if st.tokens_out:
+                return np.stack(st.tokens_out, axis=1)
+        return np.zeros((j.req.batch, 0), np.int32)
+
+    def _step_done(self, j: _Job) -> bool:
+        t = j.task
+        if self.execute:
+            st = j.state
+            if st.phase == "done":
+                return True
+            if st.phase == "decode":
+                if (st.n_tokens >= j.req.max_new_tokens
+                        or t.remaining <= 1e-15):
+                    return True
+                if (j.req.eos_id is not None and
+                        bool(np.all(st.tokens_out[-1] == j.req.eos_id))):
+                    return True
+            return False
+        return t.remaining <= 1e-15
+
     # ------------------------------------------------------------------
     def run(self, requests: List[InferenceRequest]) -> List[RequestResult]:
         """``requests`` may be a prebuilt request list or a serving-kind
@@ -589,7 +623,7 @@ class ServingEngine:
                     j.state = PreemptibleExecutor.restore(j.state)
                     parked.resume(j)
             if j.state is None and self.execute:
-                j.state = j.executor.start(self._batch_dict(j.req))
+                j.state = self._start(j)
                 self.kvs[d].register(j.req.rid, 0, dev_clock[d])
             t.state = TaskState.RUNNING
             t.device = d
@@ -638,9 +672,7 @@ class ServingEngine:
             t.completion = t_done
             t.state = TaskState.DONE
             self.kvs[d].release(j.req.rid)
-            toks = (np.stack(j.state.tokens_out, axis=1)
-                    if self.execute and j.state and j.state.tokens_out
-                    else np.zeros((j.req.batch, 0), np.int32))
+            toks = self._take_tokens(j)
             n_dec = (0 if self._models[j.req.arch][0].cfg.encoder_only
                      else t.total_nodes - j.executor.n_periods + 1)
             j.result = RequestResult(
@@ -682,22 +714,6 @@ class ServingEngine:
             dev_clock[d] += dt_wall
             devices[d].busy_time += dt_wall
             t.executed = min(t.isolated_time, t.executed + dt)
-
-        def step_done(j: _Job) -> bool:
-            t = j.task
-            if self.execute:
-                st = j.state
-                if st.phase == "done":
-                    return True
-                if st.phase == "decode":
-                    if (len(st.tokens_out) >= j.req.max_new_tokens
-                            or t.remaining <= 1e-15):
-                        return True
-                    if (j.req.eos_id is not None and
-                            bool(np.all(st.tokens_out[-1] == j.req.eos_id))):
-                        return True
-                return False
-            return t.remaining <= 1e-15
 
         # ---- failures (crash = KILL-style restart: the device's tensor
         # state is gone; in virtual mode a durable checkpoint restores) --
@@ -845,7 +861,7 @@ class ServingEngine:
                     preempt(d, j, cand, dec.mechanism)
             j = running[d]
             exec_one_step(d, j)
-            if step_done(j):
+            if self._step_done(j):
                 complete(d, j)
                 settle_drain(d, dev_clock[d])
 
@@ -1054,7 +1070,7 @@ class ServingEngine:
                 dev_clock[di] += self.kvs[di].register(
                     j.req.rid, ctx_bytes(j), now)
             if j.state is None and self.execute:
-                j.state = j.executor.start(self._batch_dict(j.req))
+                j.state = self._start(j)
             t.state = TaskState.RUNNING
             t.device = di
             while len(slots[di]) <= si:
@@ -1107,9 +1123,7 @@ class ServingEngine:
             t.completion = t_done
             t.state = TaskState.DONE
             self.kvs[di].release(j.req.rid)
-            toks = (np.stack(j.state.tokens_out, axis=1)
-                    if self.execute and j.state and j.state.tokens_out
-                    else np.zeros((j.req.batch, 0), np.int32))
+            toks = self._take_tokens(j)
             # decoded-token count: decode nodes + the first token emitted
             # at prefill completion (0 for encoder-only architectures)
             n_dec = (0 if enc_only[j.req.arch]
@@ -1206,22 +1220,6 @@ class ServingEngine:
             si = devices[di].residents.index(victim_t)
             preempt_slot(di, si, slots[di][si], dec, now)
 
-        def step_done(j: _Job) -> bool:
-            t = j.task
-            if self.execute:
-                st = j.state
-                if st.phase == "done":
-                    return True
-                if st.phase == "decode":
-                    if (len(st.tokens_out) >= j.req.max_new_tokens
-                            or t.remaining <= 1e-15):
-                        return True
-                    if (j.req.eos_id is not None and
-                            bool(np.all(st.tokens_out[-1] == j.req.eos_id))):
-                        return True
-                return False
-            return t.remaining <= 1e-15
-
         def run_iteration(di: int) -> None:
             """Advance every resident of ``di`` by one step, batched."""
             dv = devices[di]
@@ -1269,7 +1267,7 @@ class ServingEngine:
             dev_clock[di] = t_end + kv_lat
             dv.busy_time += wall
             for si, j, node, dt, nsteps in plan:
-                if step_done(j):
+                if self._step_done(j):
                     complete_slot(di, si, j)
                 elif dv.role == "prefill" and j.task.phase == "decode":
                     # pool hand-off: prefill done, the decode pool takes

@@ -85,7 +85,9 @@ def test_spans_nest_and_count_steps_and_preemptions(olmo, tmp_path):
     assert names.count("executor.first_token") == len(reqs)
     n_tokens = sum(r.tokens.shape[1] for r in eng.completed)
     assert names.count("executor.decode") == n_tokens - len(reqs)
-    assert names.count("executor.sync") == n_tokens - len(reqs)
+    # every decoded token is read once: late, or waiting on its own step
+    assert (names.count("executor.sync") + names.count("executor.read_deferred")
+            == n_tokens - len(reqs))
     assert names.count("engine.complete") == len(reqs)
     # every span but the gauge lies inside one pass of the main loop
     loops = [(s, e) for n, s, e, _ in spans if n == "engine.iteration"]
@@ -94,10 +96,15 @@ def test_spans_nest_and_count_steps_and_preemptions(olmo, tmp_path):
             assert any(ls <= s and e <= le for ls, le in loops), n
     begun = [a["rid"] for n, _, _, a in spans if n == "engine.begin"]
     assert set(begun) == {r.rid for r in reqs}
-    # a first token ends inside its prefill period; a decode's sync inside it
-    for inner, outer in (("executor.first_token", "executor.prefill"),
-                         ("executor.sync", "executor.decode")):
-        hosts = [(s, e) for n, s, e, _ in spans if n == outer]
+    # a first token ends inside its prefill period; a late read inside the
+    # next decode; a waiting read inside a decode, a checkpoint or a
+    # completion
+    for inner, outer in (("executor.first_token", {"executor.prefill"}),
+                         ("executor.read_deferred", {"executor.decode"}),
+                         ("executor.sync", {"executor.decode",
+                                            "executor.checkpoint",
+                                            "engine.complete"})):
+        hosts = [(s, e) for n, s, e, _ in spans if n in outer]
         for n, s, e, _ in spans:
             if n == inner:
                 assert any(hs <= s and e <= he for hs, he in hosts), n
@@ -163,7 +170,7 @@ def test_device_bytes_counts_every_array_the_state_holds(olmo):
 
     def arrays(st):
         leaves = jax.tree.leaves((st.h, st.img_h, st.cache_slices, st.cache,
-                                  st.last_logits))
+                                  st.last_logits, st.tok, st.pos_dev))
         return sum(a.nbytes for a in leaves)
     while st.phase == "prefill":
         st = ex.step(st)
@@ -175,3 +182,21 @@ def test_device_bytes_counts_every_array_the_state_holds(olmo):
     assert st.device_bytes() == (st.context_bytes() + st.cache_bytes()
                                  + (st.img_h.nbytes if st.img_h is not None
                                     else 0))
+
+
+def test_late_reads_are_spanned_once_per_token(olmo, tmp_path):
+    # one request alone: every decoded token but the last is read one step
+    # late, inside the next step's decode span; the last in its own step
+    eng = ServingEngine(olmo, cfg=EngineConfig(policy="fcfs"))
+    reqs = _requests(n=1, max_new=6)
+    spans = _program_spans(tmp_path, lambda: eng.run(reqs))
+    names = [n for n, _, _, _ in spans]
+    assert names.count("executor.decode") == 5
+    assert names.count("executor.read_deferred") == 4
+    assert names.count("executor.sync") == 1
+    decodes = [(s, e) for n, s, e, _ in spans if n == "executor.decode"]
+    reads = [(s, e) for n, s, e, _ in spans
+             if n in ("executor.read_deferred", "executor.sync")]
+    # the first decode reads nothing; each later one reads one token
+    for (ds, de), (rs, re_) in zip(decodes[1:], reads):
+        assert ds <= rs and re_ <= de
