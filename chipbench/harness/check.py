@@ -12,10 +12,11 @@ reference's.
 """
 from __future__ import annotations
 
-import importlib
 from typing import Dict, List, Optional
 
 import numpy as np
+
+from chipbench.harness import catalog
 
 
 def sample(records, seed: int, min_tokens: int, max_requests: int) -> List:
@@ -41,16 +42,12 @@ def sample(records, seed: int, min_tokens: int, max_requests: int) -> List:
     return out
 
 
-def reference(spec: Dict):
-    return importlib.import_module(f"chipbench.reference.{spec['reference']}")
-
-
 def gaps(spec: Dict, params, prompt: np.ndarray, served: np.ndarray,
          quantize: Optional[str] = None) -> np.ndarray:
     """Gap below the reference's best logit at each served position: of the
     served token, or with ``quantize`` of the token that the reference in
     that precision puts first."""
-    ref = reference(spec)
+    ref = catalog.reference(spec)
     seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     rows = np.arange(len(prompt) - 1, len(seq))
     want = ref.logits(spec, params, seq, rows)

@@ -2,13 +2,23 @@
 
 A configuration is a JSON file under ``chipbench/configs/`` in the key
 names of the model's published ``config.json``, holding the sizes as run.
-:func:`arch_config` states it as the program's ``ArchConfig``; the file
-never imports a widths module from ``src/``.
+Its ``reference`` key names its family, ``chipbench/families/<name>.py``
+(found by ``catalog.family``), which gives:
 
-:func:`make_weights` builds the weights from the seed on the device, in one
-jitted call, in the dtype they are served in and in the program's parameter
-layout.  The same weights are what the benchmark's plain reference reads,
-so neither side takes a number the other made.
+* ``arch_config(name, spec)``: the program's ``ArchConfig``, refusing what
+  the program cannot state;
+* ``weight_shapes(spec)``: leaf path -> ``(shape, init)`` in the program's
+  parameter layout, layers stacked on the leading axis (``init`` as
+  :func:`_leaf` reads it);
+* ``empty_nodes(spec)``: paths of the layout's nodes without leaves;
+* the counts that ``counts.py`` forwards to, and ``TINY`` and
+  ``TINY_LIMIT``, its small sizes and their limit for the CPU tests.
+
+Nothing here imports a widths module from ``src/``.  :func:`make_weights`
+builds the weights from the seed on the device, in one jitted call, in the
+dtype they are served in and in the program's parameter layout.  The same
+weights are what the benchmark's plain reference reads, so neither side
+takes a number the other made.
 """
 from __future__ import annotations
 
@@ -18,79 +28,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# model_type of the published config -> the norm it uses.  OLMo-1B uses a
-# LayerNorm with no scale or bias (arXiv:2402.00838, section 2.1); the
-# llama family an RMSNorm with a scale.
-NORMS = {"olmo": "layernorm_np", "llama": "rmsnorm"}
-
-
-def arch_config(name: str, spec: Dict):
-    """The program's ``ArchConfig`` for a configuration file."""
-    from repro.configs import ArchConfig
-    if spec["hidden_act"] != "silu":
-        raise ValueError(f"{name}: hidden_act {spec['hidden_act']!r}; "
-                         "only the SwiGLU MLP (silu) is stated")
-    if spec.get("rope_scaling") is not None:
-        raise ValueError(f"{name}: rope_scaling is not supported by the "
-                         "program; list the key in 'reduced' and set null")
-    if spec.get("attention_bias"):
-        raise ValueError(f"{name}: attention_bias is not supported here")
-    return ArchConfig(
-        name=name,
-        family="dense",
-        n_layers=int(spec["num_hidden_layers"]),
-        d_model=int(spec["hidden_size"]),
-        n_heads=int(spec["num_attention_heads"]),
-        n_kv_heads=int(spec["num_key_value_heads"]),
-        d_ff=int(spec["intermediate_size"]),
-        vocab_size=int(spec["vocab_size"]),
-        block_pattern=(("attn", "mlp"),),
-        norm=NORMS[spec["model_type"]],
-        mlp_act="silu",
-        rope_theta=float(spec["rope_theta"]),
-        tie_embeddings=bool(spec["tie_word_embeddings"]),
-        dtype=spec["serve_dtype"],
-    )
+from chipbench.harness import catalog
 
 
 def build_model(name: str, spec: Dict):
-    """``(Model, ArchConfig)`` through the program's registry."""
+    """``(Model, ArchConfig)`` through the program's registry, the config
+    stated by the configuration's family."""
     from repro.models.registry import build
-    cfg = arch_config(name, spec)
+    cfg = catalog.family(spec).arch_config(name, spec)
     return build(cfg), cfg
 
 
-def weight_shapes(spec: Dict) -> Dict:
-    """Leaf name -> (shape, fan_in or None for ones) in the program's
-    parameter layout, layers stacked on the leading axis."""
-    L = int(spec["num_hidden_layers"])
-    d = int(spec["hidden_size"])
-    hq = int(spec["num_attention_heads"])
-    hkv = int(spec["num_key_value_heads"])
-    dh = d // hq
-    f = int(spec["intermediate_size"])
-    v = int(spec["vocab_size"])
-    out = {
-        "embed/table": ((v, d), d),
-        "slots/slot0/mixer/wq": ((L, d, hq, dh), d),
-        "slots/slot0/mixer/wk": ((L, d, hkv, dh), d),
-        "slots/slot0/mixer/wv": ((L, d, hkv, dh), d),
-        "slots/slot0/mixer/wo": ((L, hq, dh, d), hq * dh),
-        "slots/slot0/ffn/w_in": ((L, d, f), d),
-        "slots/slot0/ffn/w_gate": ((L, d, f), d),
-        "slots/slot0/ffn/w_out": ((L, f, d), f),
-    }
-    if NORMS[spec["model_type"]] == "rmsnorm":
-        out["slots/slot0/norm1/scale"] = ((L, d), None)
-        out["slots/slot0/norm2/scale"] = ((L, d), None)
-        out["final_norm/scale"] = ((d,), None)
-    if not spec["tie_word_embeddings"]:
-        out["lm_head/w"] = ((d, v), d)
-    return out
-
-
-def _nest(flat: Dict) -> Dict:
+def _nest(flat: Dict, empty) -> Dict:
+    """The pytree of ``flat``'s leaves by path, with an empty node at each
+    path in ``empty``: the program keeps a norm without parameters as a
+    node with no leaves."""
     tree: Dict = {}
+    for path in empty:
+        node = tree
+        for p in path.split("/"):
+            node = node.setdefault(p, {})
     for path, leaf in flat.items():
         node = tree
         *parents, last = path.split("/")
@@ -100,21 +57,13 @@ def _nest(flat: Dict) -> Dict:
     return tree
 
 
-def _with_norms(tree: Dict) -> Dict:
-    """Add the empty norm dicts of a non-parametric LayerNorm, which the
-    program's layout keeps as leaves-free nodes."""
-    slot = tree["slots"]["slot0"]
-    slot.setdefault("norm1", {})
-    slot.setdefault("norm2", {})
-    tree.setdefault("final_norm", {})
-    return tree
-
-
 def tree_layout(spec: Dict) -> Dict:
     """The parameter pytree with every leaf a ShapeDtypeStruct."""
+    fam = catalog.family(spec)
     dtype = jnp.dtype(spec["serve_dtype"])
-    return _with_norms(_nest({k: jax.ShapeDtypeStruct(s, dtype)
-                              for k, (s, _) in weight_shapes(spec).items()}))
+    return _nest({k: jax.ShapeDtypeStruct(s, dtype)
+                  for k, (s, _) in fam.weight_shapes(spec).items()},
+                 fam.empty_nodes(spec))
 
 
 def _uniform(salt: int, seed_words, shape) -> jax.Array:
@@ -142,23 +91,37 @@ def seed_words(seed: int) -> np.ndarray:
     return np.asarray([seed & 0xFFFFFFFF, seed >> 32], np.uint32)
 
 
+def _leaf(init, salt: int, words, shape, dtype) -> jax.Array:
+    """One leaf by the initialisation its family names for it: ``"ones"``;
+    ``("uniform", fan_in)``, uniform with standard deviation
+    ``fan_in**-0.5``; or a function of the leaf's own uniform [0, 1)
+    draws, for a leaf that needs other values."""
+    if init == "ones":
+        return jnp.ones(shape, dtype)
+    u = _uniform(salt, words, shape)
+    if callable(init):
+        return init(u).astype(dtype)
+    kind, fan_in = init
+    if kind != "uniform":
+        raise ValueError(f"unknown initialisation {init!r}")
+    half_width = np.sqrt(3.0 / fan_in)
+    return ((u * 2.0 - 1.0) * half_width).astype(dtype)
+
+
 def make_weights(spec: Dict, seed: int):
-    """All weights on the default device in one jitted call: uniform with
-    standard deviation fan_in**-0.5 (norm scales are ones), in the served
-    dtype.  The seed is an argument, so one compile serves every seed."""
-    shapes = weight_shapes(spec)
+    """All weights on the default device in one jitted call, each leaf as
+    its family's leaf table says, in the served dtype.  Leaf ``i`` in the
+    sorted order of paths draws with salt ``i + 1``.  The seed is an
+    argument, so one compile serves every seed."""
+    fam = catalog.family(spec)
+    shapes = fam.weight_shapes(spec)
+    empty = fam.empty_nodes(spec)
     dtype = jnp.dtype(spec["serve_dtype"])
 
     def build(words):
-        flat = {}
-        for i, (path, (shape, fan_in)) in enumerate(sorted(shapes.items())):
-            if fan_in is None:
-                flat[path] = jnp.ones(shape, dtype)
-                continue
-            half_width = np.sqrt(3.0 / fan_in)
-            u = _uniform(i + 1, words, shape)
-            flat[path] = ((u * 2.0 - 1.0) * half_width).astype(dtype)
-        return _with_norms(_nest(flat))
+        flat = {path: _leaf(init, i + 1, words, shape, dtype)
+                for i, (path, (shape, init)) in enumerate(sorted(shapes.items()))}
+        return _nest(flat, empty)
 
     return jax.block_until_ready(jax.jit(build)(jnp.asarray(seed_words(seed))))
 

@@ -14,9 +14,14 @@ host.
   the client has sent it.  If the engine takes it in late, the wait counts.
 * A closed-loop tenant keeps its requests outstanding: its ``complete``
   subscriber sends the next one, until the window closes.
-* The window closes after ``seconds``.  The first event past it ends
-  ``run()`` (an exception raised from a subscriber); what was not complete
-  by then is censored at the close.
+* The window closes after ``seconds``: from then on the client sends
+  nothing.  It still waits for every open-loop request it sent, so that
+  the open tenants' rate is all of their work over all of its time (a
+  request that would end just past the close moves that rate by a share
+  of the time, not by its whole length), for at most ``DRAIN_S``.  The
+  first event past that wait ends ``run()`` (an exception raised from a
+  subscriber).  Latencies and every other count are censored at the
+  close, as if the wait had not been.
 """
 from __future__ import annotations
 
@@ -30,9 +35,12 @@ import numpy as np
 from chipbench.harness.trace import DISPATCH, HOLD, OPEN
 from chipbench.harness.traffic import Request, Traffic
 
+DRAIN_S = 60.0    # the longest wait past the close for open-loop requests
+
 
 class WindowClosed(Exception):
-    """Raised from a bus subscriber at the first event past the window."""
+    """Raised from a bus subscriber at the first event past the window
+    and the wait for its open-loop requests."""
 
 
 @dataclasses.dataclass
@@ -54,6 +62,12 @@ class Window:
     end: float
     records: Dict[int, Record]
     engine_returned: bool            # run() ended before the window did
+    drained: Optional[float] = None  # the wait's end: the last open-loop
+    #                                  request's completion, or the close
+
+    @property
+    def drain_end(self) -> float:
+        return self.end if self.drained is None else self.drained
 
     @property
     def seconds(self) -> float:
@@ -69,9 +83,11 @@ def _engine_request(r: Request, arch: str, arrival: float):
 
 
 def run_window(engine, traffic: Traffic, arch: str, seconds: float,
-               clock=time.perf_counter, annotate: bool = False) -> Window:
+               clock=time.perf_counter, annotate: bool = False,
+               drain_s: float = DRAIN_S) -> Window:
     """Serve ``traffic`` through ``engine.run`` for ``seconds`` of wall
-    time; the engine is used only through ``run``, ``submit``,
+    time, and then until every open-loop request has completed, for at
+    most ``drain_s``; the engine is used only through ``run``, ``submit``,
     ``events`` and the results it appends to ``completed``.  With
     ``annotate`` the window's opening, each dispatch and each hold are
     marked in the profiler's trace (see ``harness/trace.py``)."""
@@ -94,8 +110,12 @@ def run_window(engine, traffic: Traffic, arch: str, seconds: float,
             records[r.rid] = Record(r, sent=start)
             initial.append(_engine_request(r, arch, 0.0))
 
+    waiting = {r.rid for r in traffic.open}
+    stopped = [end]
+
     def close_if_past(now: float) -> None:
-        if now >= end:
+        if now >= end and (not waiting or now >= end + drain_s):
+            stopped[0] = now
             raise WindowClosed
 
     def on_submit(ev) -> None:
@@ -125,6 +145,7 @@ def run_window(engine, traffic: Traffic, arch: str, seconds: float,
         assert result.rid == ev.tid, (result.rid, ev.tid)
         rec.tokens = np.asarray(result.tokens)
         rec.n_preemptions = int(result.n_preemptions)
+        waiting.discard(ev.tid)
         if rec.req.due is None and now < end:
             nxt = traffic.next_closed(rec.req.tenant)
             records[nxt.rid] = Record(nxt, sent=now)
@@ -142,8 +163,12 @@ def run_window(engine, traffic: Traffic, arch: str, seconds: float,
         pass
     finally:
         detach()
+    if waiting:
+        drained = stopped[0]
+    else:
+        drained = max([end] + [records[r.rid].complete for r in traffic.open])
     return Window(start=start, end=end, records=records,
-                  engine_returned=returned)
+                  engine_returned=returned, drained=drained)
 
 
 def serve(cell, model, params, traffic: Traffic, seconds: float,

@@ -5,7 +5,9 @@ program's ``core.metrics`` does with ``np.percentile`` (copied so that no
 change to the program moves the yardstick).  A request due in the window
 and not complete when it closes is censored: its latency is taken as the
 time from its due time to the close, a lower bound, and it misses every
-limit.
+limit.  The interactive tenant's tokens are counted to the end of the
+wait for the open-loop requests after the close (``Window.drain_end``),
+over the time to then; the batch tenant's to the close.
 """
 from __future__ import annotations
 
@@ -68,16 +70,17 @@ def end_to_end(window, cell: Dict, mix: Dict) -> Dict[str, float]:
               for r in hi]
     ok = met(due, done, limits, window.end)
 
-    def tokens(prio):
+    def tokens(prio, upto):
         return sum(r.tokens.shape[1] for r in window.records.values()
                    if r.req.priority == prio and r.complete is not None
-                   and r.complete <= window.end)
+                   and r.complete <= upto)
     return {
         "hi_latency_p50_s": percentile(lat, 50),
         "hi_latency_p90_s": percentile(lat, 90),
         "hi_sla_share": 100.0 * sum(ok) / len(ok) if ok else None,
-        "hi_tokens_per_s": tokens(hi_prio) / window.seconds,
-        "batch_tokens_per_s": tokens(lo_prio) / window.seconds,
+        "hi_tokens_per_s": (tokens(hi_prio, window.drain_end)
+                            / (window.drain_end - window.start)),
+        "batch_tokens_per_s": tokens(lo_prio, window.end) / window.seconds,
         "n_hi": len(hi),
         "n_hi_censored": sum(c is None or c > window.end for c in done),
     }

@@ -4,7 +4,8 @@
 
 Serves the cell's traffic through the program's ``ServingEngine`` (PREMA,
 Algorithm-3 mechanism choice, one batch slot) for ``--seconds`` of wall
-time, timed from the client's side (``harness/serve.py``), then checks the
+time, and waits past the close for the open-loop requests it sent, timed
+from the client's side (``harness/serve.py``), then checks the
 served tokens against the configuration's float32 reference
 (``harness/check.py``).  The last line of standard output is one JSON
 object: ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
@@ -195,7 +196,9 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     log(f"window: {len(sent)} requests sent, {sum(r.complete is not None for r in sent)} "
         f"completed, {e2e['n_hi']} interactive ({e2e['n_hi_censored']} censored), "
         f"{sum(r.preempts for r in window.records.values())} preemptions, "
-        f"{sum(window.start <= t <= window.end for t, _ in compiles)} compiles")
+        f"{sum(window.start <= t <= window.end for t, _ in compiles)} compiles, "
+        f"open-loop requests done {window.drain_end - window.end:.3f} s "
+        f"past the close")
 
     # the reference runs after the window, with the engine's state freed
     records = list(window.records.values())

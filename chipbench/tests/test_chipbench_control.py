@@ -8,11 +8,12 @@ import pytest
 from chipbench import run
 from chipbench.harness import catalog, check, model, serve
 from chipbench.harness.traffic import Traffic
+from chipbench.tests.conftest import TINY_CELL
 
 
 @pytest.mark.parametrize("seed", [1, 2, 2 ** 33 + 3])
 def test_fp8_control_fails_where_the_program_passes(tiny, seed):
-    cell = catalog.find(tiny, "tiny-gqa.burst")
+    cell = catalog.find(tiny, TINY_CELL)
     m, cfg = model.build_model(cell.config_name, cell.spec)
     params = model.make_weights(cell.spec, seed)
     traffic = Traffic(cell.mix, cell.params, cfg.vocab_size, seed, 1.0)

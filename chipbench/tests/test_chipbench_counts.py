@@ -3,29 +3,25 @@ the program's own jitted steps, at small widths on the CPU.
 
 The counts are what the algorithm needs, so they lie under what XLA
 counts for the compiled program: by the elementwise work they leave out,
-and in prefill by the masked half of the score matrix, which the program
-computes and the count does not.  The steps are compiled with float32
-weights and one layer: XLA's CPU backend counts a bfloat16 product's
-upcasts as FLOPs, and a scan's body once whatever its trip count.
+and in prefill by what the family says the program computes beyond the
+need (for the dense decoder the masked half of the score matrix).  Each
+configuration file is taken at its family's ``TINY`` sizes, compiled with
+float32 weights and one period of layers: XLA's CPU backend counts a
+bfloat16 product's upcasts as FLOPs, and a scan's body once whatever its
+trip count.
 """
-import json
-
 import jax
 import jax.numpy as jnp
 import pytest
 
-from chipbench.harness import counts, model
-from chipbench.tests.conftest import ROOT
-
-SIZES = dict(num_hidden_layers=1, hidden_size=256, intermediate_size=512,
-             num_attention_heads=8, num_key_value_heads=2, vocab_size=512,
-             serve_dtype="float32")
+from chipbench.harness import catalog, counts, model
+from chipbench.tests.conftest import CONFIGS, config, tiny_spec
 
 
 def _spec(name):
-    spec = json.loads((ROOT / f"chipbench/configs/{name}.json").read_text())
-    spec.update(SIZES)
-    return spec
+    spec = tiny_spec(config(name), serve_dtype="float32")
+    period = len(catalog.family(spec).arch_config(name, spec).block_pattern)
+    return dict(spec, num_hidden_layers=period)
 
 
 def _cost(fn, *args, **kw):
@@ -34,7 +30,7 @@ def _cost(fn, *args, **kw):
     return c["flops"], c["bytes accessed"]
 
 
-@pytest.fixture(scope="module", params=["olmo-1b", "deepseek-coder-33b-pp8"])
+@pytest.fixture(scope="module", params=CONFIGS)
 def built(request):
     spec = _spec(request.param)
     m, cfg = model.build_model(request.param, spec)
@@ -66,12 +62,12 @@ def test_prefill_counts_under_xla(built, seq):
     xf, xb = _cost(executor._period_prefill.__wrapped__, slots, h, None,
                    cfg=cfg)
     f, b = counts.prefill_period(spec, seq)
-    masked = 2 * 2 * cfg.n_heads * cfg.d_head * seq * (seq - 1) // 2
-    assert f <= xf <= 1.05 * (f + masked), (f, masked, xf)
+    beyond = catalog.family(spec).prefill_beyond_need(spec, seq)
+    assert f <= xf <= 1.05 * (f + beyond), (f, beyond, xf)
     assert b <= xb, (b, xb)
 
 
-@pytest.mark.parametrize("name", ["olmo-1b", "deepseek-coder-33b-pp8"])
+@pytest.mark.parametrize("name", CONFIGS)
 def test_weight_bytes_match_the_weights(name):
     spec = _spec(name)
     params = jax.eval_shape(lambda: model.make_weights(spec, 0))
@@ -87,9 +83,7 @@ def test_least_time_names_its_bound():
 
 def test_full_width_weight_bytes():
     """The published sizes: olmo-1b 2.36 GB, the deepseek stage 9.41 GB."""
-    olmo = json.loads((ROOT / "chipbench/configs/olmo-1b.json").read_text())
-    ds = json.loads((ROOT / "chipbench/configs/deepseek-coder-33b-pp8.json")
-                    .read_text())
+    olmo, ds = config("olmo-1b"), config("deepseek-coder-33b-pp8")
     assert counts.weight_bytes(olmo) == pytest.approx(2.354e9, rel=1e-3)
     assert counts.weight_bytes(ds) == pytest.approx(9.410e9, rel=1e-3)
     assert counts.kv_bytes_per_token(olmo) == 128 * 1024

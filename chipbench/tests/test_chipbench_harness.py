@@ -13,13 +13,13 @@ from chipbench import run
 from chipbench.harness import catalog, peaks
 from chipbench.harness.serve import Record, Window
 from chipbench.harness.traffic import Request
-from chipbench.tests.conftest import ROOT
+from chipbench.tests.conftest import ROOT, TINY_CELL
 
 V5E = peaks.PEAKS["TPU v5 lite"]
 
 
 def _run(root, seed=2 ** 33 + 11):
-    return run.run_cell(root, "tiny-gqa.burst", seed, 1.5, False,
+    return run.run_cell(root, TINY_CELL, seed, 1.5, False,
                         require_tpu=False, peaks=V5E, log=lambda m: None)
 
 
@@ -42,9 +42,9 @@ def test_metric_added_as_a_file_is_read_by_name(tiny):
     bench["per_layer"].append({
         "name": "engine.sent_total", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "ServingEngine arrival intake",
-        "moves": "hi_latency_p90_s", "workloads": ["tiny-gqa.burst"]})
+        "moves": "hi_latency_p90_s", "workloads": [TINY_CELL]})
     (tiny / "BENCHMARK.json").write_text(json.dumps(bench))
-    cell = catalog.find(tiny, "tiny-gqa.burst")
+    cell = catalog.find(tiny, TINY_CELL)
     plen, out = (int(v) for v in sorted(cell.params["isolated_s"])[0].split("x"))
     reqs = [Request(i, "t", 9 if i < 2 else 1, np.zeros((1, plen), np.int32),
                     out, due=float(i)) for i in range(3)]

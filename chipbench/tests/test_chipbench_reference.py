@@ -1,27 +1,25 @@
 """The plain reference against the program's own forward pass, both in
 float32 on the CPU at a small size: where the configuration file states
 what the program computes, the two agree to rounding."""
-import json
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench.harness import model
+from chipbench.harness import catalog, model
 from chipbench.reference import dense
-from chipbench.tests.conftest import ROOT, TINY_SIZES
+from chipbench.tests.conftest import CONFIGS, config, tiny_spec
 
 
-@pytest.mark.parametrize("name", ["olmo-1b", "deepseek-coder-33b-pp8"])
+@pytest.mark.parametrize("name", CONFIGS)
 def test_reference_matches_the_program_in_float32(name):
-    spec = json.loads((ROOT / f"chipbench/configs/{name}.json").read_text())
-    spec.update(TINY_SIZES, serve_dtype="float32")
+    spec = tiny_spec(config(name), serve_dtype="float32")
     m, cfg = model.build_model(name, spec)
     params = model.make_weights(spec, 2 ** 32 + 9)
-    tokens = np.random.default_rng(0).integers(0, 256, 300).astype(np.int32)
+    tokens = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, 300).astype(np.int32)
     rows = np.arange(250, 300)
-    want = dense.logits(spec, params, tokens, rows)
+    want = catalog.reference(spec).logits(spec, params, tokens, rows)
     with jax.default_matmul_precision("highest"):
         for r in (rows[0], rows[-1]):
             got, _ = m.prefill(params, {"tokens": jnp.asarray(tokens[None, :r + 1])})

@@ -61,3 +61,20 @@ def test_end_to_end_of_a_window():
     assert out["batch_tokens_per_s"] == pytest.approx(10.0)
     assert out["hi_tokens_per_s"] == pytest.approx(0.8)      # 2 x 4 tokens
     assert out["n_hi_censored"] == 2
+
+
+def test_interactive_rate_runs_to_the_end_of_the_wait():
+    mix = {"tenants": [{"priority": 9}, {"priority": 1}]}
+    cell = {"sla_scale": 8.0, "isolated_s": {"128x4": 0.1}}
+    recs = [_rec(0, 9, 128, 4, 100.0, 100.5),
+            _rec(1, 9, 128, 4, 105.0, 112.0),      # done in the wait
+            _rec(2, 1, 128, 100, 100.0, 104.0),
+            _rec(3, 1, 128, 100, 104.0, 111.0)]    # batch past the close
+    w = Window(100.0, 110.0, {r.req.rid: r for r in recs}, False,
+               drained=112.0)
+    out = stats.end_to_end(w, cell, mix)
+    assert out["hi_tokens_per_s"] == pytest.approx(8 / 12.0)
+    assert out["batch_tokens_per_s"] == pytest.approx(10.0)
+    assert out["n_hi_censored"] == 1                   # latencies: the close
+    assert out["hi_latency_p90_s"] == pytest.approx(
+        np.percentile([0.5, 5.0], 90))
